@@ -8,90 +8,7 @@ all LM-family archs.
 from __future__ import annotations
 
 import dataclasses
-import os
-from typing import Mapping, Optional
-
-
-@dataclasses.dataclass(frozen=True)
-class XLAFlagsConfig:
-    """Declarative XLA latency-hiding / async-collective wiring.
-
-    The explicit-collective training path (zero1 start/finish split,
-    bucketed nonblocking legs, the ring backend's per-hop ``ppermute``
-    schedules) is built so XLA's latency-hiding scheduler can overlap
-    collectives with compute — but on GPU that scheduler and the async
-    collective lowering sit behind ``XLA_FLAGS``.  This config makes the
-    flag set declarative and :func:`apply_xla_flags` installs it
-    idempotently before the first backend-client creation.
-
-    GPU-only flags are emitted only when the resolved platform is GPU.
-    ``enable_async_collectives`` maps to
-    ``--xla_gpu_enable_pipelined_collectives``: the historical
-    ``--xla_gpu_enable_async_collectives`` spelling was removed from XLA
-    (unknown XLA_FLAGS abort the process at client creation — every
-    spelling emitted here is validated against the pinned jaxlib).
-    """
-
-    enable_async_collectives: bool = True
-    enable_latency_hiding_scheduler: bool = True
-    enable_highest_priority_async_stream: bool = True
-    triton_softmax_fusion: bool = True
-    triton_gemm_any: bool = True
-    extra: tuple[str, ...] = ()   # verbatim extra tokens, platform-agnostic
-
-    def flags(self, platform: str) -> tuple[str, ...]:
-        """The ``--flag=value`` tokens for a platform."""
-        out: list[str] = []
-        if platform == "gpu":
-            def b(v: bool) -> str:
-                return "true" if v else "false"
-            out += [
-                f"--xla_gpu_enable_pipelined_collectives={b(self.enable_async_collectives)}",
-                f"--xla_gpu_enable_latency_hiding_scheduler={b(self.enable_latency_hiding_scheduler)}",
-                f"--xla_gpu_enable_highest_priority_async_stream={b(self.enable_highest_priority_async_stream)}",
-                f"--xla_gpu_enable_triton_softmax_fusion={b(self.triton_softmax_fusion)}",
-                f"--xla_gpu_triton_gemm_any={b(self.triton_gemm_any)}",
-            ]
-        out += list(self.extra)
-        return tuple(out)
-
-
-def _flag_key(token: str) -> str:
-    return token.split("=", 1)[0]
-
-
-def apply_xla_flags(cfg: Optional[XLAFlagsConfig] = None, *,
-                    platform: Optional[str] = None,
-                    env: Optional[Mapping] = None) -> str:
-    """Merge ``cfg``'s flags into ``env["XLA_FLAGS"]``; returns the result.
-
-    * idempotent: applying twice is a no-op;
-    * preserving: an existing token with the same ``--key=`` wins (a user's
-      hand-set ``XLA_FLAGS`` — e.g. ``--xla_force_host_platform_device_count``
-      in the test battery — is never overridden);
-    * platform-aware: ``platform`` defaults to ``JAX_PLATFORMS`` /
-      ``JAX_PLATFORM_NAME`` (first entry) or ``"gpu"`` — flags must be set
-      *before* the backend client exists, so jax must not be imported to
-      sniff; absent any hint we emit the GPU set, which only a GPU client
-      ever parses.
-
-    Call before the first jax operation (launchers do this at the top of
-    ``main``): XLA_FLAGS is read when the backend client is created, not at
-    import.
-    """
-    cfg = cfg or XLAFlagsConfig()
-    env = os.environ if env is None else env
-    if platform is None:
-        hint = env.get("JAX_PLATFORMS") or env.get("JAX_PLATFORM_NAME") or ""
-        hint = hint.split(",")[0].strip().lower()
-        platform = {"cuda": "gpu", "rocm": "gpu"}.get(hint, hint) or "gpu"
-    existing = [t for t in env.get("XLA_FLAGS", "").split() if t]
-    seen = {_flag_key(t) for t in existing}
-    merged = existing + [t for t in cfg.flags(platform)
-                         if _flag_key(t) not in seen]
-    value = " ".join(merged)
-    env["XLA_FLAGS"] = value
-    return value
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)

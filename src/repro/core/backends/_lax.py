@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
 from ..emulation import prefix_fold
 
 #: jaxpr primitives that put payload on the inter-chip wire — the canonical
@@ -35,7 +34,7 @@ def rank(axes: Sequence[str]):
         return jnp.int32(0)
     r = lax.axis_index(axes[0])
     for a in axes[1:]:
-        r = r * compat.axis_size(a) + lax.axis_index(a)
+        r = r * lax.axis_size(a) + lax.axis_index(a)
     return r
 
 
@@ -104,7 +103,7 @@ def reduce_scatter_generic(x, fn: Callable, axes: Sequence[str], axis: int = 0):
     r = rank(axes)
     import math
 
-    total = math.prod(compat.axis_size(a) for a in axes) if axes else 1
+    total = math.prod(lax.axis_size(a) for a in axes) if axes else 1
     chunk = x.shape[axis] // total
     return lax.dynamic_slice_in_dim(x, r * chunk, chunk, axis=axis)
 
@@ -145,13 +144,13 @@ def _alltoall_hier_uniform(x, axes: Sequence[str], c: int):
     ``x``: ``(S*c, ...)`` rows grouped by linearized destination; returns
     the same shape grouped by linearized source."""
     a0 = axes[0]
-    A = compat.axis_size(a0)
+    A = lax.axis_size(a0)
     tail = x.shape[1:]
     if len(axes) == 1:
         return alltoall(x, (a0,), 0, 0)
     import math
 
-    R = math.prod(compat.axis_size(a) for a in axes[1:])
+    R = math.prod(lax.axis_size(a) for a in axes[1:])
     # phase 1: deliver each destination's major digit over the major axis
     # (A blocks of R*c rows); block a0 is then the data *from* major-source
     # a0, still ordered by minor destination
@@ -251,6 +250,6 @@ def scatter_from_root(x, root: int, axes: Sequence[str], axis: int = 0):
     r = rank(axes)
     import math
 
-    total = math.prod(compat.axis_size(a) for a in axes) if axes else 1
+    total = math.prod(lax.axis_size(a) for a in axes) if axes else 1
     chunk = x.shape[axis] // total
     return lax.dynamic_slice_in_dim(x, r * chunk, chunk, axis=axis)

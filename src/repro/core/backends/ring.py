@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import compat
-
 from .. import handles as H
 from . import _lax
 from .paxi import PaxiBackend, uniform_payload
@@ -62,7 +60,7 @@ def ring_reduce_scatter(x, axis_name: str, compress: Optional[str] = None):
 
     ``x`` must have leading dim divisible by the axis size. S-1 hops.
     """
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     if S == 1:
         return x
     i = lax.axis_index(axis_name)
@@ -103,7 +101,7 @@ def ring_reduce_scatter_fused(x, axis_name: str, compress: str,
     """
     from ...kernels.ring_wire import ops as wire_ops
 
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     if S == 1:
         return x
     i = lax.axis_index(axis_name)
@@ -132,7 +130,7 @@ def ring_reduce_scatter_fused(x, axis_name: str, compress: str,
 
 def ring_allgather(x, axis_name: str):
     """Inverse of ring_reduce_scatter: collect every rank's chunk. S-1 hops."""
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     if S == 1:
         return x
     i = lax.axis_index(axis_name)
@@ -159,7 +157,7 @@ def ring_scan_sum(x, axis_name: str, inclusive: bool = True,
     exactly like :func:`ring_reduce_scatter`'s wire; accumulation stays in
     the original dtype.  Error compounds with hop count (bounded in the
     multidev battery, section 6)."""
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     if S == 1:
         return x
@@ -182,7 +180,7 @@ def ring_allreduce_sum(x, axis_name: str, compress: Optional[str] = None):
     contribution travels the whole ring once).  Used by the hierarchical
     multi-axis scan for row totals, where the payload need not split into
     rank chunks.  Wire compressed per hop like the other ring schedules."""
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     if S == 1:
         return x
     perm = [(s, (s + 1) % S) for s in range(S)]

@@ -23,8 +23,6 @@ from typing import Optional, Sequence
 import jax
 from jax import lax
 
-from . import compat
-
 from . import handles as H
 from .errors import PAX_ERR_COMM, PAX_ERR_REVOKED, PaxError
 
@@ -174,7 +172,7 @@ def comm_rank_traced(info: CommInfo):
         return 0
     rank = lax.axis_index(info.axes[0])
     for a in info.axes[1:]:
-        rank = rank * compat.axis_size(a) + lax.axis_index(a)
+        rank = rank * lax.axis_size(a) + lax.axis_index(a)
     return rank
 
 

@@ -1,10 +1,7 @@
-"""Version-compat shims for the small jax API surface the ABI layer uses.
-
-The repo targets the modern jax API (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types=``) but must also run on older releases where shard_map
-lives in ``jax.experimental`` and meshes have no axis types.  Exactly the
-spirit of the source paper: one stable calling convention, negotiated
-against whatever implementation is present at runtime.
+"""Thin wrappers for the jax calls whose keyword defaults the ABI layer
+fixes once: meshes with Auto axis types, ``shard_map`` with the
+varying-manual-axes check off and an optional partial-manual axis set, and
+:func:`host_shard_map` for ABI regions called from the host every step.
 """
 from __future__ import annotations
 
@@ -12,49 +9,48 @@ from typing import Optional, Sequence
 
 import jax
 
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """Static axis size on older jax: psum of the literal 1 constant-
-        folds to the bound axis size at trace time."""
-        return jax.lax.psum(1, axis_name)
-
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(shape), tuple(names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(names)),
-        )
-    return jax.make_mesh(tuple(shape), tuple(names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(names)),
+    )
 
 
-if hasattr(jax, "shard_map"):
+def shard_map(f, *, mesh, in_specs, out_specs,
+              axis_names: Optional[Sequence[str]] = None,
+              check_vma: bool = False):
+    kwargs = {"check_vma": check_vma}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    )
 
-    def shard_map(f, *, mesh, in_specs, out_specs,
-                  axis_names: Optional[Sequence[str]] = None,
-                  check_vma: bool = False):
-        kwargs = {"check_vma": check_vma}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
 
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
+def host_shard_map(f, *, mesh, in_specs, out_specs):
+    """A :func:`shard_map` called from the host that re-runs ``f``'s Python
+    on every call and compiles only programs it has not run before.
 
-    def shard_map(f, *, mesh, in_specs, out_specs,
-                  axis_names: Optional[Sequence[str]] = None,
-                  check_vma: bool = False):
-        # older API: axes are manual unless listed in ``auto``
-        auto = frozenset()
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _shard_map(
-            f, mesh, in_specs, out_specs, check_rep=check_vma, auto=auto
-        )
+    The ABI's per-call protocol (plan start/wait, tool interposition,
+    fault injection, wait deadlines) is Python that must run on each call,
+    so the region is traced anew each time.  jax's own eager ``shard_map``
+    does that too, but it also compiles every primitive inside afresh on
+    each call; here the traced program is lowered, and its executable is
+    kept under the lowered text and reused whenever the trace comes out
+    the same."""
+    region = shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    executables: dict[str, object] = {}
+
+    def call(*args):
+        # a fresh callable per call: jit would otherwise reuse its trace
+        # and skip the Python
+        lowered = jax.jit(lambda *a: region(*a)).lower(*args)
+        key = lowered.as_text()
+        exe = executables.get(key)
+        if exe is None:
+            exe = executables[key] = lowered.compile()
+        return exe(*args)
+
+    return call

@@ -101,11 +101,8 @@ _register(H.PAX_COMPLEX128, "PAX_COMPLEX128", 16, _np("complex128"))
 # TPU extension dtypes, allocated in reserved fixed-size slots (DESIGN.md §1.4)
 if _JNP:
     _register(H.PAX_BFLOAT16, "PAX_BFLOAT16", 2, np.dtype(jnp.bfloat16))
-    try:
-        _register(H.PAX_FLOAT8_E4M3, "PAX_FLOAT8_E4M3", 1, np.dtype(jnp.float8_e4m3fn))
-        _register(H.PAX_FLOAT8_E5M2, "PAX_FLOAT8_E5M2", 1, np.dtype(jnp.float8_e5m2))
-    except Exception:  # pragma: no cover - older jax without fp8
-        pass
+    _register(H.PAX_FLOAT8_E4M3, "PAX_FLOAT8_E4M3", 1, np.dtype(jnp.float8_e4m3fn))
+    _register(H.PAX_FLOAT8_E5M2, "PAX_FLOAT8_E5M2", 1, np.dtype(jnp.float8_e5m2))
 
 N_PREDEFINED = len(_PREDEFINED)
 

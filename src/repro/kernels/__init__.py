@@ -21,6 +21,12 @@ from typing import Any, Callable, Optional
 
 import jax
 
+#: ring-wire quantization granule and TPU lane width: one int8 absmax scale
+#: per 128 wire elements.  Defined here, in the Pallas-free registry, so
+#: layout code (the ZeRO-1 flat padding) can align to it without importing
+#: the kernels.
+WIRE_BLOCK = 128
+
 #: name -> variant ("pallas" | "lax") -> lazy "module[:attr]" target
 _REGISTRY: dict[str, dict[str, Any]] = {}
 

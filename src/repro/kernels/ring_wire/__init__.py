@@ -1,6 +1,5 @@
 """Fused ring-wire Pallas kernels (see README.md)."""
 from .ops import (  # noqa: F401
-    MAX_WIRE_ELEMS,
     WIRE_BLOCK,
     hop_accum,
     hop_add_quant,

@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first initialization).  This module is the ONLY place the 512
-# placeholder devices exist; tests/benches see the real device count.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
+# count and the platform at first initialization).  This module is the ONLY
+# place the 512 placeholder devices exist; tests/benches see the real device
+# count.  The placeholders are CPU devices, and the --all children inherit
+# the setting: a dry run never takes a chip.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell against the production meshes, print memory_analysis() and

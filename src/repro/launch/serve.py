@@ -12,7 +12,7 @@ import jax
 import numpy as np
 
 import repro.configs as cfgs
-from repro.configs.base import apply_xla_flags
+from repro.launch.device import banner, use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.runtime.dist import make_dist
@@ -34,8 +34,8 @@ def main(argv=None):
                     help="prompt positions fed per engine step")
     args = ap.parse_args(argv)
 
-    # before the first jax operation: XLA_FLAGS is read at client creation
-    apply_xla_flags()
+    use_compile_cache()
+    print(banner())
     cfg = cfgs.smoke_config(args.arch) if args.smoke else cfgs.get_config(args.arch)
     api = build_model(cfg)
     mesh = make_host_mesh()

@@ -21,9 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as cfgs
-from repro.configs.base import apply_xla_flags
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.data.pipeline import DataPipeline, SyntheticSource
+from repro.launch.device import banner, use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig, warmup_cosine
@@ -51,10 +51,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO)
-    # XLA_FLAGS is parsed at backend-client creation, so install the
-    # latency-hiding/async-collective set before the first jax operation
-    # (idempotent; hand-set flags win — configs/base.py)
-    apply_xla_flags()
+    use_compile_cache()
+    print(banner())
     cfg = cfgs.smoke_config(args.arch) if args.smoke else cfgs.get_config(args.arch)
     api = build_model(cfg)
     mesh = (make_production_mesh() if args.production_mesh
@@ -72,8 +70,11 @@ def main(argv=None):
     print(f"actual params: {n_params/1e6:.2f}M")
 
     schedule = lambda step: warmup_cosine(step, warmup=args.warmup, total=args.steps)
+    # the state is donated: the step writes its new state over the old one,
+    # so the device holds one copy of the optimizer state, not two
     step_fn = jax.jit(train_loop.make_train_step(
-        api, dist, AdamWConfig(lr=args.lr), schedule=schedule))
+        api, dist, AdamWConfig(lr=args.lr), schedule=schedule),
+        donate_argnums=(0,))
 
     pipe = DataPipeline(SyntheticSource(cfg.vocab_size, seed=0),
                         global_batch=args.global_batch, seq_len=args.seq_len)

@@ -15,6 +15,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..kernels import WIRE_BLOCK
+
 
 @dataclasses.dataclass(frozen=True)
 class AdamWConfig:
@@ -95,11 +97,18 @@ def unflatten_like(vec, tree):
     return jax.tree.unflatten(treedef, out)
 
 
+def zero1_pad_multiple(dp_size: int, buckets: int = 1) -> int:
+    """The ZeRO-1 flat vector is padded to a multiple of this: every rank's
+    bucket segment then holds whole ring-wire blocks, which the fused
+    pack/unpack and hop kernels tile (``kernels.WIRE_BLOCK``)."""
+    return dp_size * max(buckets, 1) * WIRE_BLOCK
+
+
 def zero1_padded_size(n: int, dp_size: int, buckets: int = 1) -> int:
-    """Flat-vector length padded so ``dp_size * buckets`` divides it — the
-    shared contract between ``init_flat_global``, ``grad_sync.zero1_step``
+    """Flat-vector length padded to :func:`zero1_pad_multiple` — the shared
+    contract between ``init_flat_global``, ``grad_sync.zero1_step``
     bucketing and the train-loop wiring."""
-    m = dp_size * max(buckets, 1)
+    m = zero1_pad_multiple(dp_size, buckets)
     return -(-n // m) * m
 
 

@@ -13,7 +13,7 @@ plan groups and is never poisoned by a workload-comm revoke.  Each
 :meth:`~HeartbeatMonitor.beat`:
 
 * runs one ring ``sendrecv`` of the current tick over the heartbeat comm
-  (eager ``shard_map``, same cost model as a ``DecodeSync`` step);
+  (a host-called ``shard_map`` region, as in a ``DecodeSync`` step);
 * attributes non-responders through the transport's
   ``Backend.heartbeat_silent`` hook (a rank declared dead by a ``faulty:``
   schedule stops answering — the wrapper is now one *producer* of missed
@@ -83,7 +83,7 @@ class HeartbeatMonitor:
     def _build_exchange(self) -> None:
         from jax.sharding import PartitionSpec as P
 
-        from ..core.compat import shard_map
+        from ..core.compat import host_shard_map
 
         abi, hb = self.abi, self.hb_comm
         members = self.members()
@@ -96,8 +96,8 @@ class HeartbeatMonitor:
         def _beat(x):
             return abi.sendrecv(x, perm, hb)
 
-        self._exchange = shard_map(_beat, mesh=self.mesh,
-                                   in_specs=P(), out_specs=P())
+        self._exchange = host_shard_map(_beat, mesh=self.mesh,
+                                        in_specs=P(), out_specs=P())
 
     # -- test hooks ---------------------------------------------------------
     def inject_silence(self, rank: int) -> None:

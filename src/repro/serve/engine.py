@@ -98,7 +98,7 @@ class DecodeSync:
                  wait_timeout_s: Optional[float] = None) -> None:
         from jax.sharding import PartitionSpec as P
 
-        from ..core.compat import shard_map
+        from ..core.compat import host_shard_map
 
         self.abi = abi
         self.comm = comm
@@ -106,8 +106,8 @@ class DecodeSync:
         # deadline for the group/pooled waits: None blocks forever (the
         # faithful hang on a dropped broadcast); a bound turns the drop into
         # PAX_ERR_TIMEOUT, which the supervisor retries and escalates.  Read
-        # per call — the shard_map below is eager, so a live change applies
-        # to the very next token step.
+        # per call — the region below re-runs its Python on every call, so a
+        # live change applies to the very next token step.
         self.wait_timeout_s = wait_timeout_s
         ex = jax.ShapeDtypeStruct((max_batch,), jnp.int32)
         self._p_tok = abi.bcast_init(ex, 0, comm)
@@ -116,9 +116,10 @@ class DecodeSync:
                                     name=self.NAME)
 
         # the collectives bind mesh axis names, so the start/wait pair runs
-        # under an *eager* shard_map (payloads replicated): each call
+        # in a host-called shard_map region (payloads replicated): each call
         # re-drives the plan protocol and the tool interposition — one
         # before/after per token step, which is what the counting test pins
+        # — and after the first step reuses its compiled program
         def _group_call(tok, act):
             outs = abi.wait(self.group.start([tok, act]),
                             timeout_s=self.wait_timeout_s)
@@ -131,10 +132,10 @@ class DecodeSync:
             return outs[0], outs[1]
 
         spec = (P(), P())
-        self._group_call = shard_map(_group_call, mesh=mesh,
-                                     in_specs=spec, out_specs=spec)
-        self._pooled_call = shard_map(_pooled_call, mesh=mesh,
-                                      in_specs=spec, out_specs=spec)
+        self._group_call = host_shard_map(_group_call, mesh=mesh,
+                                          in_specs=spec, out_specs=spec)
+        self._pooled_call = host_shard_map(_pooled_call, mesh=mesh,
+                                           in_specs=spec, out_specs=spec)
 
     def reset(self) -> None:
         """Abort a start whose wait timed out (the post-timeout contract):

@@ -35,7 +35,7 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import PAX_SUM
 from ..core.communicator import comm_rank_traced
@@ -70,6 +70,21 @@ def _flat_opt_specs(dp_axes) -> FlatAdamState:
     return FlatAdamState(P(), dpP, dpP, dpP)
 
 
+def _region_specs(state: TrainState, dp_axes) -> TrainState:
+    """The abi step region's specs for the state, the same going in and
+    coming out: params and step replicated over the dp axes, the optimizer
+    state in its layout (ZeRO-1 flat or per-leaf)."""
+    rep = lambda tree: jax.tree.map(lambda _: P(), tree)
+    opt = (_flat_opt_specs(dp_axes) if isinstance(state.opt, FlatAdamState)
+           else rep(state.opt))
+    return TrainState(rep(state.params), opt, P())
+
+
+def _shardings(mesh, specs):
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda v: isinstance(v, P))
+
+
 def init_state(api: ModelApi, key, dist: Optional[DistContext] = None) -> TrainState:
     """Build the initial train state.
 
@@ -91,14 +106,23 @@ def init_state(api: ModelApi, key, dist: Optional[DistContext] = None) -> TrainS
     plan cache): re-init with the same (padded, dp, buckets, wire) layout
     keeps the live plans/groups untouched — zero new request slots — while
     a genuine layout change (re-sharding, elastic dp, bucket retune)
-    retires the old slots and re-plans."""
+    retires the old slots and re-plans.
+
+    In abi mode the state is committed to ``dist.mesh`` with the shardings
+    the step returns, so the jitted step sees the same input shardings on
+    every call and compiles once."""
     params = api.init(key)
     par = api.cfg.parallelism
     if dist is not None and par.grad_sync == "abi" and par.zero1:
         buckets = max(par.zero1_buckets, 1)
         with_ef = par.grad_compression == "bf16"
-        opt = adamw.init_flat_global(
-            params, dist.dp_size, buckets=buckets, with_ef=with_ef)
+        # made in place on each device: the full-length moments never sit
+        # on one device
+        opt = jax.jit(
+            lambda: adamw.init_flat_global(
+                params, dist.dp_size, buckets=buckets, with_ef=with_ef),
+            out_shardings=_shardings(dist.mesh, _flat_opt_specs(dist.dp_axes)),
+        )()
         from .grad_sync import build_zero1_plans, zero1_wire_dtype
         old = dist.zero1_plans
         if old is None or not old.matches(
@@ -111,7 +135,11 @@ def init_state(api: ModelApi, key, dist: Optional[DistContext] = None) -> TrainS
                 dist, opt.m.shape[0], buckets, par.grad_compression)
     else:
         opt = adamw.init_tree(params)
-    return TrainState(params, opt, jnp.zeros((), jnp.int32))
+    state = TrainState(params, opt, jnp.zeros((), jnp.int32))
+    if dist is None or par.grad_sync != "abi":
+        return state
+    return jax.device_put(
+        state, _shardings(dist.mesh, _region_specs(state, dist.dp_axes)))
 
 
 def _microbatched_grads(loss_fn, params, batch, n_micro: int):
@@ -223,7 +251,8 @@ def make_train_step_abi(
         with use_rules(dist.rules):
             loss, grads = _microbatched_grads(
                 lambda p, b: api.loss_fn(p, b, dist), params, batch, n_micro)
-            flat_g = pad_to(adamw.flatten(grads), dp * buckets)
+            pad = adamw.zero1_pad_multiple(dp, buckets)
+            flat_g = pad_to(adamw.flatten(grads), pad)
             n_flat = sum(int(l.size) for l in jax.tree.leaves(grads))
             # error feedback: opt.ef is this rank's full-length residual
             # exactly when compression is on (a (1,)-dummy otherwise)
@@ -234,7 +263,7 @@ def make_train_step_abi(
             # overlapped with the in-flight reduce-scatter group: this
             # rank's contiguous param slice (same layout as g_shard and as
             # the P(dp_axes)-sharded moment vectors) depends only on params
-            flat_p = pad_to(adamw.flatten(params), dp * buckets)
+            flat_p = pad_to(adamw.flatten(params), pad)
             shard_len = flat_p.shape[0] // dp
             r = comm_rank_traced(dist.abi.comms.info(dist.dp_comm))
             p_shard = jax.lax.dynamic_slice_in_dim(flat_p, r * shard_len, shard_len)
@@ -253,20 +282,15 @@ def make_train_step_abi(
             loss = dist.abi.allreduce(loss, PAX_SUM, dist.dp_comm) / dp
         return new_params, new_opt, loss, gnorm
 
-    flat_opt_specs = _flat_opt_specs(dist.dp_axes)
-
     def step_fn(state: TrainState, batch):
-        rep = lambda tree: jax.tree.map(lambda _: P(), tree)
-        zero1 = isinstance(state.opt, FlatAdamState)
+        sp = _region_specs(state, dist.dp_axes)
         f = dist.abi.shard_region(
-            body_zero1 if zero1 else body,
+            body_zero1 if isinstance(state.opt, FlatAdamState) else body,
             # step passed explicitly: closures over tracers are
             # illegal inside shard_map bodies
-            in_specs=(rep(state.params),
-                      flat_opt_specs if zero1 else rep(state.opt), P(),
+            in_specs=(sp.params, sp.opt, sp.step,
                       jax.tree.map(lambda _: P(dist.dp_axes), batch)),
-            out_specs=(rep(state.params),
-                       flat_opt_specs if zero1 else rep(state.opt), P(), P()),
+            out_specs=(sp.params, sp.opt, P(), P()),
             axis_names=set(dist.dp_axes),
         )
         new_params, new_opt, loss, gnorm = f(state.params, state.opt, state.step, batch)

@@ -12,10 +12,10 @@ Contract (see kernels/ring_wire/ref.py):
 
 Plus the plan-time selection surface (kernel registry, capability tags,
 eligibility predicates), the hlo_analysis traffic breakdown that proves
-the fusion claim, the flash-attention registry routing, and the XLA-flags
-launcher wiring.  Multi-device behaviour (the fused hops inside a real
-ring schedule, grad_sync plans at dp=2/8) lives in multidev_battery.py
-sections 9/10/12.
+the fusion claim, and the flash-attention registry routing.  Multi-device
+behaviour (the fused hops inside a real ring schedule, grad_sync plans at
+dp=2/8) lives in multidev_battery.py sections 9/10/12; compiles for the
+chip at real widths live in test_tpu_compile.py.
 """
 import numpy as np
 import pytest
@@ -148,6 +148,39 @@ def test_unpack_gathers_inverts_pack():
         np.asarray(_interleave_bucket_gathers(parts, dp)), np.asarray(flat))
 
 
+def test_multi_tile_grid_parity():
+    """Payloads longer than one row tile (with a partial last tile) keep
+    the single-tile contracts: bitwise quantize / bf16 hop / pack+unpack,
+    one quantum on the int8 hop."""
+    from repro.kernels.ring_wire.kernel import ROW_TILE
+    from repro.train.grad_sync import _transposed_bucket_parts
+
+    n = (ROW_TILE + 40) * WIRE_BLOCK
+    k1, k2 = jax.random.split(KEY)
+    x, a = _vec(k1, n), _vec(k2, n)
+    q, s = ops.quant(x, "int8", interpret=True)
+    qr, sr = ref.quant_i8_block(x)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
+    q2, _ = ops.hop_add_quant(q, s, a, "int8", interpret=True)
+    q2r, _ = ref.hop_add_quant_i8_block(q, s, a)
+    assert np.abs(np.asarray(q2, np.int32) - np.asarray(q2r, np.int32)).max() <= 1
+    w = x.astype(jnp.bfloat16)
+    o = ops.hop_accum(w, None, a, "bf16", interpret=True)
+    np.testing.assert_array_equal(np.asarray(o),
+                                  np.asarray(w.astype(jnp.float32) + a))
+
+    dp, buckets = 2, 2
+    flat = _vec(k1, dp * buckets * n)   # segments of ROW_TILE + 40 rows
+    parts = ops.pack_parts(flat, dp, buckets, jnp.bfloat16, interpret=True)
+    for p, r in zip(parts, _transposed_bucket_parts(
+            flat.astype(jnp.bfloat16), dp, buckets)):
+        np.testing.assert_array_equal(np.asarray(p), np.asarray(r))
+    f32_parts = ops.pack_parts(flat, dp, buckets, jnp.float32, interpret=True)
+    back = ops.unpack_gathers(f32_parts, dp, interpret=True)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(flat))
+
+
 # ---------------------------------------------------------------------------
 # eligibility predicates + kernel registry + capability tags
 # ---------------------------------------------------------------------------
@@ -161,11 +194,29 @@ def test_wire_eligible():
     assert not ops.wire_eligible((N,), jnp.bfloat16, **ok)     # payload dtype
     assert not ops.wire_eligible((N,), jnp.float32, compress="int8",
                                  platform="weird")
-    # TPU/GPU cap at MAX_WIRE_ELEMS; CPU interpret has no cap
-    big = (2 * ops.MAX_WIRE_ELEMS,)
+    # no size cap on any platform: the kernels tile rows
+    big = (1 << 27,)
     assert ops.wire_eligible(big, jnp.float32, compress="int8", platform="cpu")
-    assert not ops.wire_eligible(big, jnp.float32, compress="int8",
+    assert ops.wire_eligible(big, jnp.float32, compress="int8",
+                             platform="tpu")
+
+
+def test_eligible_at_qwen2_zero1_dp4_sizes():
+    """qwen2-0.5b's ZeRO-1 layout at dp=4 on TPU (494,032,768 parameters):
+    the padded flat vector takes the fused pack, and one rank's ~123.5M
+    element hop chunk takes the fused hops — the kernels tile rows, so
+    no size falls back to lax."""
+    from repro.optim.adamw import zero1_padded_size
+
+    padded = zero1_padded_size(494_032_768, 4)
+    assert padded % (4 * WIRE_BLOCK) == 0
+    assert ops.pack_eligible(padded, 4, 1, platform="tpu")
+    for compress in ("int8", "bf16"):
+        assert ops.wire_eligible((padded // 4,), jnp.float32, compress,
                                  platform="tpu")
+    # a segment of whole wire blocks or shorter than one block tiles; a
+    # ragged segment longer than one block does not
+    assert not ops.pack_eligible(4 * (WIRE_BLOCK + 8), 4, 1, platform="tpu")
 
 
 def test_pack_eligible():
@@ -264,45 +315,3 @@ def test_attention_flash_matches_xla():
     out_xla, _ = attention(params, x, cfg_xla, positions=positions)
     np.testing.assert_allclose(np.asarray(out_flash), np.asarray(out_xla),
                                atol=3e-5, rtol=3e-5)
-
-
-# ---------------------------------------------------------------------------
-# XLA-flags launcher wiring (satellite: latency-hiding declarative config)
-# ---------------------------------------------------------------------------
-def test_apply_xla_flags_gpu_set_and_idempotency():
-    from repro.configs.base import XLAFlagsConfig, apply_xla_flags
-
-    env = {}
-    first = apply_xla_flags(platform="gpu", env=env)
-    assert "--xla_gpu_enable_latency_hiding_scheduler=true" in first.split()
-    assert "--xla_gpu_enable_pipelined_collectives=true" in first.split()
-    # the removed historical spelling must never be emitted (fatal at
-    # client creation on the pinned jaxlib)
-    assert "--xla_gpu_enable_async_collectives" not in first
-    assert apply_xla_flags(platform="gpu", env=env) == first  # idempotent
-
-    # an existing token with the same key wins
-    env2 = {"XLA_FLAGS": "--xla_gpu_enable_latency_hiding_scheduler=false"}
-    merged = apply_xla_flags(platform="gpu", env=env2).split()
-    assert "--xla_gpu_enable_latency_hiding_scheduler=false" in merged
-    assert "--xla_gpu_enable_latency_hiding_scheduler=true" not in merged
-
-    # unrelated user flags are preserved
-    env3 = {"XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-    merged3 = apply_xla_flags(platform="gpu", env=env3).split()
-    assert merged3[0] == "--xla_force_host_platform_device_count=8"
-
-    # cpu platform: only `extra` tokens, no GPU flags
-    env4 = {}
-    cpu = apply_xla_flags(XLAFlagsConfig(extra=("--x=1",)),
-                          platform="cpu", env=env4)
-    assert cpu == "--x=1"
-    assert apply_xla_flags(platform="cpu", env={}) == ""
-
-
-def test_xla_flags_config_off_values():
-    from repro.configs.base import XLAFlagsConfig
-
-    off = XLAFlagsConfig(enable_latency_hiding_scheduler=False)
-    toks = off.flags("gpu")
-    assert "--xla_gpu_enable_latency_hiding_scheduler=false" in toks
