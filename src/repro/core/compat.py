@@ -9,6 +9,8 @@ from typing import Optional, Sequence
 
 import jax
 
+from ..runtime import spans
+
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> jax.sharding.Mesh:
     """``jax.make_mesh`` with Auto axis types."""
@@ -39,18 +41,30 @@ def host_shard_map(f, *, mesh, in_specs, out_specs):
     does that too, but it also compiles every primitive inside afresh on
     each call; here the traced program is lowered, and its executable is
     kept under the lowered text and reused whenever the trace comes out
-    the same."""
+    the same.  The callable counts its ``calls`` and ``compiles``, and
+    each call's lowering, compile and run are ``pax.abi.region.*``
+    spans."""
     region = shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     executables: dict[str, object] = {}
 
     def call(*args):
-        # a fresh callable per call: jit would otherwise reuse its trace
+        # a fresh function per call: jit would otherwise reuse its trace
         # and skip the Python
-        lowered = jax.jit(lambda *a: region(*a)).lower(*args)
-        key = lowered.as_text()
+        def abi_region(*a):
+            return region(*a)
+
+        call.calls += 1
+        with spans.span(spans.REGION_LOWER):
+            lowered = jax.jit(abi_region).lower(*args)
+            key = lowered.as_text()
         exe = executables.get(key)
         if exe is None:
-            exe = executables[key] = lowered.compile()
-        return exe(*args)
+            call.compiles += 1
+            with spans.span(spans.REGION_COMPILE):
+                exe = executables[key] = lowered.compile()
+        with spans.span(spans.REGION_RUN):
+            return exe(*args)
 
+    call.calls = 0       # regions run
+    call.compiles = 0    # programs compiled (lowered text not seen before)
     return call

@@ -37,12 +37,14 @@ page).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
 from .kv_cache import BlockAllocator
 from .scheduler import DECODE, Scheduler
 
@@ -64,6 +66,10 @@ class Request:
     retries: int = 0                   # replay count (supervisor recovery)
     expired: bool = False              # deadline passed; done, no more tokens
     failed: bool = False               # dropped after max_retries replays
+    # -- time stamps on time.perf_counter ----------------------------------
+    t_submit: Optional[float] = None   # accepted by ServeEngine.submit
+    t_admit: Optional[float] = None    # first admitted to a slot
+    t_first: Optional[float] = None    # first token appended
 
 
 def sample(logits, key, temperature: float, top_k: int):
@@ -137,6 +143,16 @@ class DecodeSync:
         self._pooled_call = host_shard_map(_pooled_call, mesh=mesh,
                                            in_specs=spec, out_specs=spec)
 
+    @property
+    def calls(self) -> int:
+        """Regions run, group and pooled paths together."""
+        return self._group_call.calls + self._pooled_call.calls
+
+    @property
+    def compiles(self) -> int:
+        """Region programs compiled, group and pooled paths together."""
+        return self._group_call.compiles + self._pooled_call.compiles
+
     def reset(self) -> None:
         """Abort a start whose wait timed out (the post-timeout contract):
         force the group and member plans inactive so the next token step
@@ -147,11 +163,13 @@ class DecodeSync:
 
     def step(self, tokens: np.ndarray, active: np.ndarray):
         """ONE group start/wait for the whole token step."""
-        tok, act = self._group_call(jnp.asarray(tokens), jnp.asarray(active))
-        tok, act = np.asarray(tok), np.asarray(act)
-        # corruption folded into the wire payload in-trace surfaces here,
-        # at materialization (no-op when integrity mode is off)
-        self.abi.verify_clean((tok, act), "decode-tp sync")
+        with spans.span(spans.SERVE_SYNC):
+            tok, act = self._group_call(jnp.asarray(tokens),
+                                        jnp.asarray(active))
+            tok, act = np.asarray(tok), np.asarray(act)
+            # corruption folded into the wire payload in-trace surfaces
+            # here, at materialization (no-op when integrity mode is off)
+            self.abi.verify_clean((tok, act), "decode-tp sync")
         return tok, act
 
     def step_pooled(self, tokens: np.ndarray, active: np.ndarray):
@@ -183,12 +201,16 @@ class ServeEngine:
         self.eos_id = eos_id
         self.seed = seed
         self._base_key = jax.random.PRNGKey(seed)
-        self.stats = {"prefill_tokens": 0, "decode_steps": 0,
+        # prefill_positions counts chunk positions computed (pads too),
+        # decode_rows the decoding rows summed over decode steps
+        self.stats = {"prefill_tokens": 0, "prefill_positions": 0,
+                      "decode_steps": 0, "decode_rows": 0,
                       "prefill_chunks": 0, "requests": 0, "steps": 0,
                       "expired": 0}
         self.last_expired: list = []   # requests expired by the last step()
         self.paged = self.cfg.family in ("dense", "moe")
         self.decode_sync: Optional[DecodeSync] = None
+        spans.install_gc_span()
 
         if self.paged:
             from ..models import transformer
@@ -205,17 +227,21 @@ class ServeEngine:
                 self.cfg, num_blocks, block_size)
             # the two compiled steps of the serving loop, shapes frozen:
             # prefill (1, chunk), decode (max_batch, 1); pages donated so
-            # the slab updates in place on device
-            self._prefill_chunk_fn = jax.jit(
-                lambda p, toks, pages, table, start: transformer.
-                prefill_chunk_paged(p, toks, pages, table, start,
-                                    self.cfg, dist),
-                donate_argnums=(2,))
-            self._decode_paged = jax.jit(
-                lambda p, tok, pages, tables, lengths: transformer.
-                decode_step_paged(p, tok, pages, tables, lengths,
-                                  self.cfg, dist),
-                donate_argnums=(2,))
+            # the slab updates in place on device.  Named functions, so
+            # the programs are jit_prefill_chunk_paged and
+            # jit_decode_step_paged in a profile
+            def prefill_chunk_paged(p, toks, pages, table, start):
+                return transformer.prefill_chunk_paged(
+                    p, toks, pages, table, start, self.cfg, dist)
+
+            def decode_step_paged(p, tok, pages, tables, lengths):
+                return transformer.decode_step_paged(
+                    p, tok, pages, tables, lengths, self.cfg, dist)
+
+            self._prefill_chunk_fn = jax.jit(prefill_chunk_paged,
+                                             donate_argnums=(2,))
+            self._decode_paged = jax.jit(decode_step_paged,
+                                         donate_argnums=(2,))
             if dist is not None:
                 self.decode_sync = DecodeSync(dist.abi, dist.tp_comm,
                                               max_batch, dist.mesh)
@@ -240,6 +266,8 @@ class ServeEngine:
 
     def _append(self, req: Request, tok: int) -> None:
         req.out_tokens.append(tok)
+        if req.t_first is None:
+            req.t_first = time.perf_counter()
         if self.eos_id is not None and tok == self.eos_id:
             req.done = True
         if len(req.out_tokens) >= req.max_new_tokens:
@@ -258,6 +286,7 @@ class ServeEngine:
         if req.submit_step is None:
             req.submit_step = self.stats["steps"]  # deadline clock starts now
         self.scheduler.submit(req)
+        req.t_submit = time.perf_counter()
         self.stats["requests"] += 1
 
     def rebuild_decode_sync(self, abi, comm, mesh,
@@ -299,75 +328,89 @@ class ServeEngine:
         """One serving step: admit waiting requests into free slots, run at
         most one prefill chunk, then one decode step for every decoding
         slot (ending in one ``decode-tp`` plan-group start/wait)."""
-        sched = self.scheduler
-        self.stats["steps"] += 1
-        # deadline pass first: an expired request frees its blocks before
-        # admission, so its capacity funds the queue head this very step
-        self.last_expired = sched.expire(self.stats["steps"])
-        self.stats["expired"] += len(self.last_expired)
-        sched.admit()
-        i = sched.prefill_slot()
-        if i is not None:
-            self._prefill_step(i)
-        dslots = sched.decode_slots()
-        if dslots:
-            self._decode_step(dslots)
+        with spans.span(spans.SERVE_STEP):
+            sched = self.scheduler
+            self.stats["steps"] += 1
+            with spans.span(spans.SERVE_ADMIT):
+                # deadline pass first: an expired request frees its blocks
+                # before admission, so its capacity funds the queue head
+                # this very step
+                self.last_expired = sched.expire(self.stats["steps"])
+                self.stats["expired"] += len(self.last_expired)
+                sched.admit()
+            i = sched.prefill_slot()
+            if i is not None:
+                self._prefill_step(i)
+            dslots = sched.decode_slots()
+            if dslots:
+                self._decode_step(dslots)
 
     def _prefill_step(self, i: int) -> None:
         """Feed the next B=1 prompt chunk of slot ``i`` into its KV blocks;
         on the final chunk, sample the request's first token."""
-        seq = self.scheduler.slots[i]
-        req, C = seq.req, self.prefill_chunk
-        start = seq.fed
-        real = np.asarray(req.prompt[start:start + C], np.int32)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :len(real)] = real
-        logits, self._pages = self._prefill_chunk_fn(
-            self.params, jnp.asarray(chunk), self._pages,
-            jnp.asarray(seq.table[None]), jnp.int32(start))
-        seq.fed = start + C
-        self.stats["prefill_tokens"] += int(len(real))
-        self.stats["prefill_chunks"] += 1
-        if seq.prefill_done:
-            last = (seq.prompt_len - 1) - start    # last real row of chunk
-            tok = self._sample_one(np.asarray(logits[0, last]), req)
-            self._append(req, tok)
-            if req.done:
-                self.scheduler.finish(i)
-            else:
-                seq.state = DECODE
+        with spans.span(spans.SERVE_PREFILL):
+            seq = self.scheduler.slots[i]
+            req, C = seq.req, self.prefill_chunk
+            start = seq.fed
+            real = np.asarray(req.prompt[start:start + C], np.int32)
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :len(real)] = real
+            logits, self._pages = self._prefill_chunk_fn(
+                self.params, jnp.asarray(chunk), self._pages,
+                jnp.asarray(seq.table[None]), jnp.int32(start))
+            seq.fed = start + C
+            self.stats["prefill_tokens"] += int(len(real))
+            self.stats["prefill_positions"] += C
+            self.stats["prefill_chunks"] += 1
+            if seq.prefill_done:
+                last = (seq.prompt_len - 1) - start  # last real row of chunk
+                tok = self._sample_one(np.asarray(logits[0, last]), req)
+                self._append(req, tok)
+                if req.done:
+                    self.scheduler.finish(i)
+                else:
+                    seq.state = DECODE
 
     def _decode_step(self, dslots: list[int]) -> None:
         """One full-width decode step.  Inactive slots run too (fixed
         shape), but with length 0 and an all-null block table: their writes
         land in the reserved null block and their logits are discarded."""
-        sched = self.scheduler
-        B = self.max_batch
-        toks = np.zeros((B, 1), np.int32)
-        lengths = np.zeros((B,), np.int32)
-        tables = np.zeros((B, sched.table_width), np.int32)  # NULL_BLOCK rows
-        for i in dslots:
-            seq = sched.slots[i]
-            toks[i, 0] = seq.req.out_tokens[-1]
-            lengths[i] = seq.prompt_len + len(seq.req.out_tokens) - 1
-            tables[i] = seq.table
-        logits, self._pages = self._decode_paged(
-            self.params, jnp.asarray(toks), self._pages,
-            jnp.asarray(tables), jnp.asarray(lengths))
-        self.stats["decode_steps"] += 1
-        logits_np = np.asarray(logits)
-        sampled = np.zeros((B,), np.int32)
-        active = np.zeros((B,), np.int32)
-        for i in dslots:
-            sampled[i] = self._sample_one(logits_np[i], sched.slots[i].req)
-            active[i] = 1
-        if self.decode_sync is not None:
-            sampled, active = self.decode_sync.step(sampled, active)
-        for i in dslots:
-            seq = sched.slots[i]
-            self._append(seq.req, int(sampled[i]))
-            if seq.req.done:
-                sched.finish(i)
+        with spans.span(spans.SERVE_DECODE):
+            sched = self.scheduler
+            B = self.max_batch
+            with spans.span(spans.SERVE_DECODE_DISPATCH):
+                toks = np.zeros((B, 1), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                # inactive rows keep NULL_BLOCK tables
+                tables = np.zeros((B, sched.table_width), np.int32)
+                for i in dslots:
+                    seq = sched.slots[i]
+                    toks[i, 0] = seq.req.out_tokens[-1]
+                    lengths[i] = seq.prompt_len + len(seq.req.out_tokens) - 1
+                    tables[i] = seq.table
+                logits, self._pages = self._decode_paged(
+                    self.params, jnp.asarray(toks), self._pages,
+                    jnp.asarray(tables), jnp.asarray(lengths))
+            self.stats["decode_steps"] += 1
+            self.stats["decode_rows"] += len(dslots)
+            with spans.span(spans.SERVE_DECODE_WAIT):
+                logits.block_until_ready()
+            with spans.span(spans.SERVE_DECODE_COPY):
+                logits_np = np.asarray(logits)
+            sampled = np.zeros((B,), np.int32)
+            active = np.zeros((B,), np.int32)
+            with spans.span(spans.SERVE_SAMPLE):
+                for i in dslots:
+                    sampled[i] = self._sample_one(logits_np[i],
+                                                  sched.slots[i].req)
+                    active[i] = 1
+            if self.decode_sync is not None:
+                sampled, active = self.decode_sync.step(sampled, active)
+            for i in dslots:
+                seq = sched.slots[i]
+                self._append(seq.req, int(sampled[i]))
+                if seq.req.done:
+                    sched.finish(i)
 
     # -- legacy static batching (ssm/hybrid: no KV pages) --------------------
     def _run_static(self, requests: list[Request]) -> None:

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 from typing import Optional
 
 import numpy as np
@@ -142,6 +143,8 @@ class Scheduler:
             except KVCacheOOM:
                 break                      # pool full: wait for an evict
             self.waiting.popleft()
+            if req.t_admit is None:        # a replay keeps its first stamp
+                req.t_admit = time.perf_counter()
             self.slots[i] = Sequence(
                 req=req, handles=handles,
                 table=block_table_view(self.alloc, handles, self.table_width),
