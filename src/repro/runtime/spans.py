@@ -16,9 +16,12 @@ The catalog:
 ``pax.serve.prefill``       one prefill chunk: build, dispatch, first token
 ``pax.serve.decode``        one decode step, sampling and sync included
 ``pax.serve.decode.dispatch`` the step's host arrays and the jit call
-``pax.serve.decode.wait``   waiting for the decode program's logits
-``pax.serve.decode.copy``   the ``(max_batch, vocab)`` logits to the host
-``pax.serve.sample``        sampling every decoding row (one per step)
+``pax.serve.sample``        dispatching the row programs, one a decoding row,
+                            and the stack of their tokens (one span a step,
+                            before the wait)
+``pax.serve.decode.wait``   waiting for the tokens: the decode program and
+                            the row programs queued behind it
+``pax.serve.decode.copy``   the ``(max_batch,)`` tokens to the host, one copy
 ``pax.serve.sync``          ``DecodeSync.step``: the ``decode-tp`` group
 ``pax.abi.region.lower``    a host-called ABI region traced and lowered
 ``pax.abi.region.compile``  the region compiled (first call of a program)

@@ -23,6 +23,11 @@ Architecture (dense/moe families):
   ``fold_in(fold_in(PRNGKey(seed), rid), step)``; a request's sampled
   tokens never depend on which other requests share its batch (the old
   engine-wide ``split`` chain did — that was the PR-8 bugfix).
+* **sampling on the device** — each sampled row is one call of a compiled
+  row program (``jit_sample_row``) on a row of the logits that stays on
+  the device, dispatched before anything waits, so the row programs queue
+  behind the decode program; only the ``(max_batch,)`` tokens reach the
+  host, stacked on the device into one array and copied once.
 * **decode plan group** — per-token tensor-parallel control-plane sync
   (sampled tokens + active mask broadcast from tp root 0, the
   sample-on-rank-0 idiom) is built ONCE at engine init as two persistent
@@ -72,14 +77,28 @@ class Request:
     t_first: Optional[float] = None    # first token appended
 
 
-def sample(logits, key, temperature: float, top_k: int):
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / temperature
+def greedy(logits):
+    """The greedy branch of :func:`sample`: the first largest logit."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def draw(logits, key, temperature, top_k: int):
+    """The sampled branch of :func:`sample`, shared with the engine's
+    compiled row program.  ``temperature`` (a float, or a traced scalar) is
+    cast to the logits' dtype before the divide, which is what dividing by
+    a Python float does; the Gumbel noise is drawn in the logits' dtype and
+    the top-k is exact."""
+    logits = logits / jnp.asarray(temperature).astype(logits.dtype)
     if top_k > 0:
         vals, _ = jax.lax.top_k(logits, top_k)
         logits = jnp.where(logits < vals[..., -1:], -1e30, logits)
     return jax.random.categorical(key, logits).astype(jnp.int32)
+
+
+def sample(logits, key, temperature: float, top_k: int):
+    if temperature <= 0.0:
+        return greedy(logits)
+    return draw(logits, key, temperature, top_k)
 
 
 class DecodeSync:
@@ -202,11 +221,12 @@ class ServeEngine:
         self.seed = seed
         self._base_key = jax.random.PRNGKey(seed)
         # prefill_positions counts chunk positions computed (pads too),
-        # decode_rows the decoding rows summed over decode steps
+        # decode_rows the decoding rows summed over decode steps,
+        # sampled_rows the rows drawn by the row program (greedy ones not)
         self.stats = {"prefill_tokens": 0, "prefill_positions": 0,
                       "decode_steps": 0, "decode_rows": 0,
                       "prefill_chunks": 0, "requests": 0, "steps": 0,
-                      "expired": 0}
+                      "expired": 0, "sampled_rows": 0}
         self.last_expired: list = []   # requests expired by the last step()
         self.paged = self.cfg.family in ("dense", "moe")
         self.decode_sync: Optional[DecodeSync] = None
@@ -250,6 +270,35 @@ class ServeEngine:
                 lambda p, tok, cache, idx: api.decode_step(
                     p, tok, cache, idx, dist))
 
+        # the row program: one sampled token from one row of logits left on
+        # the device.  Built per engine and keyed inside the trace through
+        # self._req_key, so a replaced _req_key is traced in; the
+        # temperature is traced (one compile per row width, dtype and
+        # top_k), and the program is jit_sample_row in a profile.  The
+        # base key is an argument, bound to self._base_key while tracing:
+        # as a constant it would make a new program, and a compile of the
+        # exact top-k (tens of seconds on a TPU), for every engine seed
+        self._sample_traces = 0
+
+        def sample_row(row, base_key, rid, step, temperature, top_k):
+            self._sample_traces += 1
+            own, self._base_key = self._base_key, base_key
+            try:
+                key = self._req_key(rid, step)
+            finally:
+                self._base_key = own
+            return draw(row, key, temperature, top_k)
+
+        self._sample_row = jax.jit(sample_row, static_argnames=("top_k",))
+
+        # a decode step's tokens, one per slot, as one (max_batch,) array:
+        # one copy to the host instead of one a row.  Idle slots hold 0
+        def stack_tokens(tokens):
+            return jnp.stack(tokens)
+
+        self._stack_tokens = jax.jit(stack_tokens)
+        self._no_token = jnp.zeros((), jnp.int32)
+
     # -- per-request RNG (batch-composition-independent) --------------------
     def _req_key(self, rid: int, step: int):
         """Key for request ``rid``'s ``step``-th sampled token: depends on
@@ -257,12 +306,24 @@ class ServeEngine:
         return jax.random.fold_in(
             jax.random.fold_in(self._base_key, rid), step)
 
-    def _sample_one(self, row_logits: np.ndarray, req: Request) -> int:
+    @property
+    def sample_compiles(self) -> int:
+        """Traces of the row program (one per row width, dtype and
+        ``top_k``)."""
+        return self._sample_traces
+
+    def _sample_one(self, row_logits: jax.Array, req: Request) -> jax.Array:
+        """``req``'s next token from its row of logits on the device, as a
+        0-d device array (not waited for)."""
         if req.temperature <= 0.0:
-            return int(np.argmax(row_logits))
-        key = self._req_key(req.rid, len(req.out_tokens))
-        return int(sample(jnp.asarray(row_logits), key,
-                          float(req.temperature), int(req.top_k)))
+            return greedy(row_logits)
+        self.stats["sampled_rows"] += 1
+        # uint32, as fold_in reads them; fixed dtypes keep one signature
+        return self._sample_row(row_logits, self._base_key,
+                                np.uint32(req.rid),
+                                np.uint32(len(req.out_tokens)),
+                                np.float32(req.temperature),
+                                top_k=int(req.top_k))
 
     def _append(self, req: Request, tok: int) -> None:
         req.out_tokens.append(tok)
@@ -364,7 +425,7 @@ class ServeEngine:
             self.stats["prefill_chunks"] += 1
             if seq.prefill_done:
                 last = (seq.prompt_len - 1) - start  # last real row of chunk
-                tok = self._sample_one(np.asarray(logits[0, last]), req)
+                tok = int(self._sample_one(logits[0, last], req))
                 self._append(req, tok)
                 if req.done:
                     self.scheduler.finish(i)
@@ -393,17 +454,25 @@ class ServeEngine:
                     jnp.asarray(tables), jnp.asarray(lengths))
             self.stats["decode_steps"] += 1
             self.stats["decode_rows"] += len(dslots)
-            with spans.span(spans.SERVE_DECODE_WAIT):
-                logits.block_until_ready()
-            with spans.span(spans.SERVE_DECODE_COPY):
-                logits_np = np.asarray(logits)
-            sampled = np.zeros((B,), np.int32)
-            active = np.zeros((B,), np.int32)
+            # the row programs queue behind the decode program on the
+            # device; only the (max_batch,) tokens come to the host.  The
+            # rows come from one split program (list(logits)): indexing
+            # logits[i] would dispatch several eager ops a row
             with spans.span(spans.SERVE_SAMPLE):
+                rows = list(logits)
+                drawn = [self._no_token] * B
                 for i in dslots:
-                    sampled[i] = self._sample_one(logits_np[i],
-                                                  sched.slots[i].req)
-                    active[i] = 1
+                    tok = self._sample_one(rows[i], sched.slots[i].req)
+                    # a replaced sampler may hand back a Python int
+                    drawn[i] = (tok if isinstance(tok, jax.Array)
+                                else np.int32(tok))
+                drawn = self._stack_tokens(drawn)
+            with spans.span(spans.SERVE_DECODE_WAIT):
+                drawn.block_until_ready()
+            with spans.span(spans.SERVE_DECODE_COPY):
+                sampled = np.asarray(drawn)
+            active = np.zeros((B,), np.int32)
+            active[dslots] = 1
             if self.decode_sync is not None:
                 sampled, active = self.decode_sync.step(sampled, active)
             for i in dslots:
@@ -446,9 +515,9 @@ class ServeEngine:
             self._append_live(cur, requests)
 
     def _sample_rows(self, logits, requests: list[Request]) -> np.ndarray:
-        logits_np = np.asarray(logits)
-        return np.asarray([self._sample_one(logits_np[i], r)
-                           for i, r in enumerate(requests)], np.int32)
+        drawn = [self._sample_one(row, r)
+                 for row, r in zip(list(logits), requests)]
+        return np.asarray(jax.device_get(drawn), np.int32)
 
     def _append_live(self, cur, requests: list[Request]) -> None:
         for i, r in enumerate(requests):

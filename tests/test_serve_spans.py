@@ -80,6 +80,11 @@ def test_serving_spans_nest_in_the_profile(model, tmp_path):
                   S.SERVE_DECODE_COPY, S.SERVE_SAMPLE, S.SERVE_SYNC,
                   S.REGION_LOWER, S.REGION_RUN):
             assert sum(_inside(x, d) for x in named(n)) == 1, n
+        # the row programs are dispatched before the wait for their tokens
+        order = [next(x for x in named(n) if _inside(x, d)) for n in (
+            S.SERVE_DECODE_DISPATCH, S.SERVE_SAMPLE, S.SERVE_DECODE_WAIT,
+            S.SERVE_DECODE_COPY, S.SERVE_SYNC)]
+        assert all(a.end <= b.start for a, b in zip(order, order[1:]))
     # one lowering per decode step; the one compile at the first
     assert len(named(S.REGION_LOWER)) == len(decodes)
     compiles = named(S.REGION_COMPILE)
