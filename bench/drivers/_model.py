@@ -5,7 +5,6 @@ from __future__ import annotations
 import dataclasses
 
 from .. import harness as H
-from .. import weights as W
 
 
 def model_config(cell: H.Cell, rehearse: bool):
@@ -22,12 +21,13 @@ def model_config(cell: H.Cell, rehearse: bool):
                                **cell.config.get("overrides", {}))
 
 
-def dims_for(cell: H.Cell, cfg, rehearse: bool) -> W.Dims:
-    eps = float(cell.config.get("rms_norm_eps",
-                                cell.config.get("layernorm_epsilon", 1e-6)))
-    ran = W.Dims.of(cfg, eps)
+def dims_for(cell: H.Cell, cfg, rehearse: bool):
+    """The sizes of what the program runs, by the cell's family module; at
+    full size they have to be those the configuration file states."""
+    family = H.family(cell)
+    ran = family.program_dims(cfg, cell.config)
     if not rehearse:
-        stated = W.Dims.from_file(cell.config)
+        stated = family.file_dims(cell.config)
         if stated != ran:
             raise H.BenchError(f"{cell.config['arch']}: the configuration "
                                f"file says {stated}, the program runs {ran}")

@@ -27,8 +27,6 @@ import time
 import numpy as np
 
 from .. import harness as H
-from .. import weights as W
-from ..reference import dense as ref
 from ..reference import sample as ref_sample
 from ._model import dims_for, model_config
 
@@ -102,11 +100,24 @@ def _mark_programs(eng, spans: H.Spans) -> None:
         jax.block_until_ready(logits)
 
 
+#: ``engine.stats`` entries whose window deltas the ``window`` line prints
+STATS = ("decode_rows", "prefill_positions", "sampled_rows")
+
+
+def program_counters(eng) -> dict:
+    """The program's counters: ``STATS`` of ``engine.stats`` and
+    ``DecodeSync``'s ``calls`` and ``compiles``."""
+    return {**{k: eng.stats[k] for k in STATS},
+            "decode_sync.calls": eng.decode_sync.calls,
+            "decode_sync.compiles": eng.decode_sync.compiles}
+
+
 def _window(run: H.Run, eng, Request, offered, lead_in_s: float) -> list[Rec]:
     """Serve ``offered`` from ``lead_in_s`` before the window to its close.
     Returns every request offered by the close; ``run.window`` is the
-    measured part, and ``run.records["lead_in_start"]`` where the lead-in
-    began."""
+    measured part, ``run.records["lead_in_start"]`` where the lead-in
+    began, and ``run.records["counters"]`` the program's counters' deltas
+    over the window."""
     spans = run.spans
     recs = []
     run.records["lead_in_start"] = time.perf_counter()
@@ -118,8 +129,11 @@ def _window(run: H.Run, eng, Request, offered, lead_in_s: float) -> list[Rec]:
     traced = False
     trace_from = end - min(TRACE_SECONDS, run.seconds)
     steps = 0
+    opened = None
     while True:
         now = time.perf_counter()
+        if opened is None and now >= t0:
+            opened = program_counters(eng)
         if now >= end:
             break
         if run.trace and not traced and now >= trace_from:
@@ -160,6 +174,8 @@ def _window(run: H.Run, eng, Request, offered, lead_in_s: float) -> list[Rec]:
                 r.tokens.extend([te] * (n - len(r.tokens)))
             if r.req.done:
                 del live[rid]
+    run.records["counters"] = {k: v - opened[k]
+                               for k, v in program_counters(eng).items()}
     run.records["queue_end"] = len(eng.scheduler.waiting)
     run.records["queue_start"] = sum(r.due < t0 and (
         r.admit is None or r.admit > t0) for r in recs)
@@ -191,10 +207,11 @@ def _sample(recs: list[Rec], seed: int, want_tokens: int,
     return out
 
 
-def served_gaps(seed: int, engine_seed: int, dims: W.Dims, sample: list[Rec],
+def served_gaps(family, seed: int, engine_seed: int, dims, sample: list[Rec],
                 *, pad_to: int, rows_pad: int, control: bool = False) -> dict:
     """Widest gaps by which served tokens lie below the float32
-    reference's choice at their positions, drawn under the engine's keys
+    reference's choice (the ``family`` module's ``logits_at``) at their
+    positions, drawn under the engine's keys
     (``engine_seed``, request id, token index): ``gap`` (compared) and
     ``strict`` (without the allowance at the top-k boundary).  With
     ``control``, also ``control``: the widest gap of the tokens that the
@@ -207,8 +224,8 @@ def served_gaps(seed: int, engine_seed: int, dims: W.Dims, sample: list[Rec],
         rows.append(np.arange(len(p) - 1, len(p) - 1 + len(out)))
         toks.append(out)
     kw = dict(pad_to=pad_to, rows_pad=rows_pad)
-    logits = ref.logits_at(seed, dims, seqs, rows, **kw)
-    lower = ref.logits_at(seed, dims, seqs, rows, matmul="fp8", **kw) \
+    logits = family.logits_at(seed, dims, seqs, rows, **kw)
+    lower = family.logits_at(seed, dims, seqs, rows, matmul="fp8", **kw) \
         if control else [None] * len(sample)
 
     def widest(use_lower: bool) -> tuple[float, float]:
@@ -239,6 +256,7 @@ class Served:
 
         cell = run.cell
         self.Request = Request
+        self.family = H.family(cell)
         self.cfg = cfg = model_config(cell, run.rehearse)
         self.dims = dims_for(cell, cfg, run.rehearse)
         self.engine_cfg, self.traffic, self.rate = settings(cell, run.rehearse)
@@ -250,7 +268,7 @@ class Served:
         self.clog = CompileLog()
         api = build_model(cfg)
         self.params = jax.block_until_ready(
-            W.program_params(api, self.dims, run.seed))
+            self.family.program_params(api, self.dims, run.seed))
         self.engine_seed = run.seed % 2**31
         self._make = lambda: ServeEngine(
             api, self.params,
@@ -296,7 +314,8 @@ def window_facts(run: H.Run, recs, rate: float) -> dict:
             "queue_end": run.records["queue_end"],
             "generator_late_max_s": max(lateness, default=0.0),
             "generator_late_p95_s": _p(lateness, 95),
-            "engine_steps": run.records["steps"]}
+            "engine_steps": run.records["steps"],
+            "counters": run.records["counters"]}
 
 
 def limit_for(run: H.Run) -> float:
@@ -339,6 +358,7 @@ def run(run: H.Run) -> None:
     run.attempted = len(due)
     run.failed = sum(r.error is not None or r.req.expired for r in due)
     print(json.dumps({**window_facts(run, recs, sv.rate),
+                      "family": sv.family.__name__,
                       "compiles_setup": run.counters["compiles_setup"],
                       "compiles_window": run.counters["compiles_window"],
                       "setup_s": run.setup_s}), flush=True)
@@ -352,7 +372,7 @@ def run(run: H.Run) -> None:
         return
     tr = time.perf_counter()
     control = bool(run.extra.get("control"))
-    got = served_gaps(run.seed, sv.engine_seed, sv.dims, sample,
+    got = served_gaps(sv.family, run.seed, sv.engine_seed, sv.dims, sample,
                       pad_to=sv.engine_cfg["max_seq"],
                       rows_pad=int(sv.traffic["output_len"]["max"]),
                       control=control)

@@ -21,28 +21,10 @@ import time
 import numpy as np
 
 from .. import harness as H
-from .. import weights as W
-from ..reference import train as ref
 from ._model import dims_for, model_config
 
 TRACE_SECONDS = 8.0
 CHECKED_STEPS = 3
-
-#: program parameter path -> reference leaf name
-_LEAF = {("embed", "tok"): "embed/tok", ("embed", "unembed"): "embed/unembed",
-         ("final_norm", "scale"): "embed/final_norm",
-         ("layers", "ln1", "scale"): "layers/ln1",
-         ("layers", "ln2", "scale"): "layers/ln2",
-         ("layers", "mlp", "wg"): "layers/wg", ("layers", "mlp", "wi"): "layers/wi",
-         ("layers", "mlp", "wo"): "layers/wd"}
-
-
-def leaf_name(path) -> str:
-    keys = tuple(getattr(k, "key", k) for k in path)
-    if keys[:2] == ("layers", "attn"):
-        return f"layers/{keys[2]}"
-    return _LEAF[keys]
-
 
 class Trained:
     def __init__(self, run: H.Run):
@@ -56,13 +38,14 @@ class Trained:
         from repro.train import train_loop
 
         cell = run.cell
+        self.family = H.family(cell)
         self.cfg = cfg = model_config(cell, run.rehearse)
         self.dims = dims_for(cell, cfg, run.rehearse)
         t = dict(cell.spec["trainer"])
         if run.rehearse:
             t.update(cell.spec.get("rehearsal", {}).get("trainer", {}))
         self.global_batch, self.seq_len = t["global_batch"], t["seq_len"]
-        self.adam = ref.Adam(lr=float(t["lr"]))
+        self.adam = self.family.Adam(lr=float(t["lr"]))
         self.gen = H.load_module(
             cell.root / "traffic" / f"{cell.traffic['generator']}.py", "traffic")
         self.traffic, self.seed = cell.traffic, run.seed
@@ -74,7 +57,8 @@ class Trained:
                                f"{cell.chips} chips")
         state = train_loop.init_state(api, jax.random.PRNGKey(0), self.dist)
         rep = NamedSharding(self.dist.mesh, P())
-        params = jax.device_put(W.program_params(api, self.dims, run.seed), rep)
+        params = jax.device_put(
+            self.family.program_params(api, self.dims, run.seed), rep)
         self.state = state._replace(params=params)
         a = self.adam
         self.step = jax.jit(train_loop.make_train_step(
@@ -83,7 +67,8 @@ class Trained:
                                         grad_clip=a.grad_clip)),
             donate_argnums=(0,))
         paths = jax.tree_util.tree_flatten_with_path(params)[0]
-        self.leaves = [(leaf_name(p), int(x.size)) for p, x in paths]
+        self.leaves = [(self.family.leaf_name(p), int(x.size))
+                       for p, x in paths]
 
     def batch(self, k: int, host: bool = False):
         import jax.numpy as jnp
@@ -125,7 +110,8 @@ class Trained:
                 a.astype(jnp.float32) - b.astype(jnp.float32)))), p, q))(
             self.state.params, p0)
         flat = jax.tree_util.tree_flatten_with_path(change)[0]
-        out["change_leaves"] = {leaf_name(p): float(x) for p, x in flat}
+        out["change_leaves"] = {self.family.leaf_name(p): float(x)
+                                for p, x in flat}
         return out
 
     def free(self) -> None:
@@ -232,10 +218,10 @@ def run(run: H.Run) -> None:
                       "setup_s": run.setup_s, **first}), flush=True)
 
     batches = [tr.batch(k, host=True) for k in range(1, CHECKED_STEPS + 1)]
-    dims, adam = tr.dims, tr.adam
+    fam, dims, adam = tr.family, tr.dims, tr.adam
     tr.free()
     t = time.perf_counter()
-    want = ref.train(run.seed, dims, batches, adam, devs)
+    want = fam.train(run.seed, dims, batches, adam, devs)
     got = compare(first, want)
     for k in CHECKS:
         run.checks[k] = (got[k][0], limits[k])
@@ -244,7 +230,7 @@ def run(run: H.Run) -> None:
             "worst": {k: got[k] for k in ("grad_leaf_gap", "change_leaf_gap")},
             "left_out_of_change": got["left_out"]}
     if run.extra.get("control"):
-        ctrl = compare(ref.train(run.seed, dims, batches, adam, devs,
+        ctrl = compare(fam.train(run.seed, dims, batches, adam, devs,
                                  matmul="fp8"), want)
         run.extra["control_gaps"] = {k: ctrl[k][0] for k in run.checks}
         fact["control"] = run.extra["control_gaps"]

@@ -9,6 +9,11 @@ sits in a file of its own under ``bench/``:
   traffic name, the numbers ``correct`` compares with their limits, why;
 * ``configs/<config>.json``  — the published sizes as run, ``reduced``,
   ``assumed``, ``source``, the deployment;
+* ``reference/<module>.py``  — the model family the configuration names
+  in ``reference.module`` (``dense`` when absent): the sizes from the file
+  and from the program, the seeded parameter tree, the float32
+  reference's ``logits_at`` with its ``fp8`` control, operations per
+  token, and, where the family trains, its train reference;
 * ``traffic/<traffic>.json`` — parameters for the generator it names,
   ``traffic/<generator>.py``;
 * ``metrics/<metric>.py``    — ``read(run) -> float | None``;
@@ -95,6 +100,14 @@ def load_cell(name: str, bench: dict, root: Path = BENCH) -> Cell:
     config = load_json(root / "configs" / f"{spec['config']}.json")
     traffic = load_json(root / "traffic" / f"{spec['traffic']}.json")
     return Cell(name, entry, spec, config, traffic, root)
+
+
+def family(cell: Cell):
+    """The module of the cell's model family, ``reference/<module>.py``,
+    where ``<module>`` is the configuration file's ``reference.module``
+    (``dense`` when the key is absent)."""
+    name = cell.config.get("reference", {}).get("module", "dense")
+    return load_module(cell.root / "reference" / f"{name}.py", "reference")
 
 
 def metrics_for(bench: dict, cell: str, trace: bool) -> list[dict]:

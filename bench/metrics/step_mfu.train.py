@@ -1,8 +1,8 @@
 """Model step: model FLOP utilization of the whole train step, the
-forward and backward operations per token (bench/flops.py, recomputation
-left out) times the window's tokens per second, over the chips' bf16
-peak (bench/peaks.json)."""
-from .. import flops, harness
+forward and backward operations per token (the cell's family module,
+recomputation left out) times the window's tokens per second, over the
+chips' bf16 peak (bench/peaks.json)."""
+from .. import harness
 
 
 def read(run):
@@ -11,5 +11,6 @@ def read(run):
         return None
     tps = len(steps) * run.records["tokens_per_step"] / (steps[-1][1] - run.window[0])
     peak = harness.peaks(run.device["kind"])["bf16_flops_per_s"]
-    per_token = flops.train_flops_per_token(run.records["dims"], run.records["seq_len"])
+    per_token = harness.family(run.cell).train_flops_per_token(
+        run.records["dims"], run.records["seq_len"])
     return 100.0 * per_token * tps / (run.device["count"] * peak)

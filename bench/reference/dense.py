@@ -14,6 +14,17 @@ whole score matrix.
 :func:`mm_fp8` for the control (every matrix product's operands rounded
 to float8 e4m3 with a per-tensor scale, the precision one step below the
 configuration's bfloat16).
+
+This file is also the dense family's module, which the harness finds by
+the configuration file's ``reference.module`` (``dense`` when absent;
+``bench/harness.py:family``).  A family module gives the drivers and
+readers: ``file_dims(config)`` and ``program_dims(cfg, config)``, the
+sizes the file states and those the program runs (equal at full size;
+they carry ``param_dtype``); ``program_params(api, dims, seed)``;
+``logits_at``; ``forward_flops_per_token(dims, context)`` and
+``train_flops_per_token(dims, seq_len)``; and, where the family trains,
+``Adam``, ``train`` and ``leaf_name``.  Here they come from
+``bench/weights.py``, ``bench/flops.py`` and ``reference/train.py``.
 """
 from __future__ import annotations
 
@@ -23,7 +34,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import flops as _flops
 from .. import weights as W
+
+program_params = W.program_params
+forward_flops_per_token = _flops.forward_flops_per_token
+train_flops_per_token = _flops.train_flops_per_token
+
+
+def file_dims(config: dict) -> W.Dims:
+    """The sizes a configuration file of ``bench/configs`` states."""
+    return W.Dims.from_file(config)
+
+
+def program_dims(cfg, config: dict) -> W.Dims:
+    """The sizes of what the program runs (a ``repro.configs``
+    ModelConfig), with the norm's epsilon, which that does not carry, from
+    the configuration file."""
+    eps = float(config.get("rms_norm_eps",
+                           config.get("layernorm_epsilon", 1e-6)))
+    return W.Dims.of(cfg, eps)
+
 
 #: query rows per attention block
 Q_BLOCK = 1024
@@ -136,3 +167,35 @@ def logits_at(seed: int, dims: W.Dims, seqs: list[np.ndarray],
             out.append(np.asarray(_logits(e, x[jnp.asarray(idx)], dims, mm))
                        [:len(r)])
         return out
+
+
+#: program parameter path -> reference leaf name
+_LEAF = {("embed", "tok"): "embed/tok", ("embed", "unembed"): "embed/unembed",
+         ("final_norm", "scale"): "embed/final_norm",
+         ("layers", "ln1", "scale"): "layers/ln1",
+         ("layers", "ln2", "scale"): "layers/ln2",
+         ("layers", "mlp", "wg"): "layers/wg", ("layers", "mlp", "wi"): "layers/wi",
+         ("layers", "mlp", "wo"): "layers/wd"}
+
+
+def leaf_name(path) -> str:
+    """The train reference's name (``reference/train.py:leaf_norms``) of
+    the program's parameter at ``path`` (a ``tree_flatten_with_path`` key
+    path)."""
+    keys = tuple(getattr(k, "key", k) for k in path)
+    if keys[:2] == ("layers", "attn"):
+        return f"layers/{keys[2]}"
+    return _LEAF[keys]
+
+
+def Adam(**kw):
+    """``reference/train.py:Adam`` (imported here: that module imports
+    this one)."""
+    from .train import Adam
+    return Adam(**kw)
+
+
+def train(*args, **kw) -> dict:
+    """``reference/train.py:train``."""
+    from .train import train
+    return train(*args, **kw)

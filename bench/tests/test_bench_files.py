@@ -1,9 +1,10 @@
 """Every cell, configuration, traffic mix and metric named in
 BENCHMARK.json has its file under bench/, and the harness finds a new
-cell and metric added as new files only."""
+cell, metric and model family added as new files only."""
 import json
 import re
 import shutil
+import time
 
 import pytest
 
@@ -82,3 +83,62 @@ def test_a_new_cell_and_metric_are_found_from_new_files_alone(tmp_path):
     assert names == ["dummy_ms.serve"]
     run = H.Run(cell, 1, 1.0, True, False, 0.0)
     assert H.reduce_metrics(run, bench) == {"dummy_ms.serve": {"value": 42.0, "unit": "ms"}}
+
+
+def _with_family(tmp_path, module: str, cells: list[str]):
+    """A copy of bench/ with a configuration ``twin`` (qwen2-0.5b's file
+    naming ``module``) and a twin of each of ``cells`` on it, added as new
+    files, and BENCHMARK.json's entries for them; returns ``(root,
+    bench, twins, files)``, ``files`` those the copy had before."""
+    root = tmp_path / "bench"
+    shutil.copytree(H.BENCH, root, ignore=shutil.ignore_patterns("__pycache__"))
+    files = sorted(p.relative_to(root) for p in root.rglob("*") if p.is_file())
+    config = H.load_json(root / "configs" / "qwen2-0.5b.json")
+    config["reference"]["module"] = module
+    (root / "configs" / "twin.json").write_text(json.dumps(config))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "twin", "source": config["source"],
+                             "file": "bench/configs/twin.json", "reduced": [],
+                             "why": "the qwen2-0.5b file under a new family"})
+    twins = []
+    for name in cells:
+        spec = H.load_json(root / "workloads" / f"{name}.json")
+        twin = name.replace("qwen2-0.5b", "twin")
+        spec["config"] = "twin"
+        (root / "workloads" / f"{twin}.json").write_text(json.dumps(spec))
+        bench["workloads"].append({"name": twin, "config": "twin",
+                                   "traffic": spec["traffic"],
+                                   "chips": spec["chips"], "why": "a twin"})
+        for m in bench["end_to_end"] + bench["per_layer"]:
+            if name in m.get("workloads", []):
+                m["workloads"].append(twin)
+        twins.append(twin)
+    return root, bench, twins, files
+
+
+def test_a_new_family_needs_new_files_only(tmp_path):
+    root, bench, (twin,), files = _with_family(
+        tmp_path, "dense_twin", ["qwen2-0.5b.serve.chat"])
+    (root / "reference" / "dense_twin.py").write_text(
+        '"""The dense family under another name."""\n'
+        "from .dense import *  # noqa: F401,F403\n")
+    cell = H.load_cell(twin, bench, root)
+    assert H.family(cell).__name__ == "bench.reference.dense_twin"
+    run = H.Run(cell, 2**31 + 5, 6.0, False, True, time.perf_counter())
+    H.load_module(H.BENCH / "drivers" / "serve.py", "drivers").run(run)
+    line = H.result_line(run, H.reduce_metrics(run, bench))
+    assert line["correct"] and line["attempted"] > 0 and line["failed"] == 0
+    assert {"cpu.ttft_p95_ms", "cpu.setup_s"} <= set(line["metrics"])
+    for f in files:
+        assert (root / f).read_bytes() == (H.BENCH / f).read_bytes(), f
+
+
+@pytest.mark.parametrize("name", ["qwen2-0.5b.serve.chat",
+                                  "qwen2-0.5b.train.zero1-dp4"])
+def test_an_unknown_family_module_is_refused_naming_its_file(tmp_path, name):
+    _, bench, (twin,), _ = _with_family(tmp_path, "no_such_family", [name])
+    cell = H.load_cell(twin, bench, tmp_path / "bench")
+    run = H.Run(cell, 1, 1.0, False, True, time.perf_counter())
+    driver = H.load_module(H.BENCH / "drivers" / f"{cell.kind}.py", "drivers")
+    with pytest.raises(H.BenchError, match=r"reference/no_such_family\.py"):
+        driver.run(run)

@@ -1,4 +1,5 @@
-"""bench/flops.py against a count by hand for qwen2-0.5b."""
+"""bench/flops.py against a count by hand for qwen2-0.5b, and the MFU
+reader, which takes the count from the cell's family module."""
 
 from bench import flops, harness
 from bench.weights import Dims
@@ -30,3 +31,23 @@ def test_params_match_the_published_count():
     norms = d.layers * 2 * d.d_model + d.d_model
     biases = d.layers * (d.heads + 2 * d.kv_heads) * d.head_dim
     assert flops.matmul_params(d) + norms + biases == 494_032_768
+
+
+def test_step_mfu_by_hand():
+    name = "qwen2-0.5b.train.zero1-dp4"
+    spec = harness.load_json(harness.BENCH / "workloads" / f"{name}.json")
+    bench = harness.load_bench()
+    bench["workloads"] = [{"name": name, "config": spec["config"],
+                           "traffic": spec["traffic"], "chips": spec["chips"]}]
+    cell = harness.load_cell(name, bench)
+    run = harness.Run(cell, 1, 5.0, False, False, 0.0)
+    run.window = (10.0, 15.0)
+    run.records.update(steps=[(4, 12.0, 1.0), (5, 14.0, 1.0)], dims=qwen(),
+                       tokens_per_step=16 * 2048, seq_len=2048)
+    run.device.update(kind="TPU v5 lite", count=4)
+    reader = harness.load_module(
+        harness.BENCH / "metrics" / "step_mfu.train.py", "metrics")
+    # 2 steps of 16 x 2048 tokens in the 4 s to the last one's end
+    want = 100 * flops.train_flops_per_token(qwen(), 2048) * (2 * 32768 / 4.0) \
+        / (4 * 197e12)
+    assert reader.read(run) == want
