@@ -1,20 +1,28 @@
 """Named host spans of the serving path, for the profiler.
 
-Each span is a ``jax.profiler.TraceAnnotation`` with a fixed name and no
-metadata.  With no profiler running, entering and leaving one costs about
-as much as ``contextlib.nullcontext``; while ``jax.profiler.start_trace``
+Each span is a ``jax.profiler.TraceAnnotation`` with a fixed name; two
+carry arguments (below), set only while a profiler records.  With no
+profiler running, entering and leaving one costs about as much as
+``contextlib.nullcontext``; while ``jax.profiler.start_trace``
 (or a profiler server started by ``jax.profiler.start_server``) records,
 the spans land in the trace's host plane, on the same clock as the
 device's operations, so each idle stretch of the device can be put down
-to what the host was doing.  Spans of one thread nest.
+to what the host was doing.  Spans of one thread nest.  The arguments land
+as the event's stats (``ProfileData`` event ``.stats``).
 
 The catalog:
 
 =========================== ===============================================
 ``pax.serve.step``          one ``ServeEngine.step`` (parent of the rest)
 ``pax.serve.admit``         deadline expiry and admission
-``pax.serve.prefill``       one prefill chunk: build, dispatch, first token
-``pax.serve.decode``        one decode step, sampling and sync included
+``pax.serve.prefill``       one prefill chunk: build, dispatch, first token;
+                            arguments ``start`` (the chunk's first
+                            position), ``real`` (prompt positions in it)
+                            and ``chunk`` (its width, pads included)
+``pax.serve.decode``        one decode step, sampling and sync included;
+                            arguments ``rows`` (decoding rows) and
+                            ``context`` (positions they attend, summed:
+                            each row's length + 1)
 ``pax.serve.decode.dispatch`` the step's host arrays and the jit call
 ``pax.serve.sample``        dispatching the row programs, one a decoding row,
                             and the stack of their tokens (one span a step,

@@ -409,11 +409,13 @@ class ServeEngine:
     def _prefill_step(self, i: int) -> None:
         """Feed the next B=1 prompt chunk of slot ``i`` into its KV blocks;
         on the final chunk, sample the request's first token."""
-        with spans.span(spans.SERVE_PREFILL):
+        with spans.span(spans.SERVE_PREFILL) as sp:
             seq = self.scheduler.slots[i]
             req, C = seq.req, self.prefill_chunk
             start = seq.fed
             real = np.asarray(req.prompt[start:start + C], np.int32)
+            if sp.is_enabled():
+                sp.set_metadata(start=start, real=len(real), chunk=C)
             chunk = np.zeros((1, C), np.int32)
             chunk[0, :len(real)] = real
             logits, self._pages = self._prefill_chunk_fn(
@@ -436,7 +438,7 @@ class ServeEngine:
         """One full-width decode step.  Inactive slots run too (fixed
         shape), but with length 0 and an all-null block table: their writes
         land in the reserved null block and their logits are discarded."""
-        with spans.span(spans.SERVE_DECODE):
+        with spans.span(spans.SERVE_DECODE) as sp:
             sched = self.scheduler
             B = self.max_batch
             with spans.span(spans.SERVE_DECODE_DISPATCH):
@@ -452,6 +454,9 @@ class ServeEngine:
                 logits, self._pages = self._decode_paged(
                     self.params, jnp.asarray(toks), self._pages,
                     jnp.asarray(tables), jnp.asarray(lengths))
+            if sp.is_enabled():
+                sp.set_metadata(rows=len(dslots),
+                                context=int(lengths.sum()) + len(dslots))
             self.stats["decode_steps"] += 1
             self.stats["decode_rows"] += len(dslots)
             # the row programs queue behind the decode program on the

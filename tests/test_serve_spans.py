@@ -102,6 +102,27 @@ def test_serving_spans_nest_in_the_profile(model, tmp_path):
     assert eng._decode_paged.__name__ == "decode_step_paged"
 
 
+def test_prefill_and_decode_spans_carry_their_arguments(model, tmp_path):
+    from bench.metrics import _span_args
+    eng = _engine(model)
+    reqs = _requests(model[0])
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(reqs)
+    got = _span_args.read_spans(tmp_path, (S.SERVE_PREFILL, S.SERVE_DECODE))
+    prefill = [a for e, a in got if e.name == S.SERVE_PREFILL]
+    decode = [a for e, a in got if e.name == S.SERVE_DECODE]
+    assert len(prefill) == eng.stats["prefill_chunks"]
+    assert all(a["chunk"] == 4 and 0 < a["real"] <= 4 for a in prefill)
+    assert sum(a["real"] for a in prefill) == eng.stats["prefill_tokens"]
+    assert sorted(a["start"] for a in prefill) == sorted(
+        s for r in reqs for s in range(0, len(r.prompt), 4))
+    assert len(decode) == eng.stats["decode_steps"]
+    assert sum(a["rows"] for a in decode) == eng.stats["decode_rows"]
+    # each decoding row attends its prompt and every token before its own
+    assert sum(a["context"] for a in decode) == sum(
+        len(r.prompt) + k for r in reqs for k in range(1, len(r.out_tokens)))
+
+
 def test_gc_span_installs_once():
     S.install_gc_span()
     S.install_gc_span()
